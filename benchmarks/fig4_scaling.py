@@ -122,6 +122,8 @@ def main(quick: bool = False, smoke: bool = False, distributed: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.bench import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(allow_abbrev=False)
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",

@@ -63,6 +63,8 @@ def main(quick: bool = False, smoke: bool = False, out: str | None = None,
 
 
 if __name__ == "__main__":
+    from repro.bench import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",

@@ -76,6 +76,8 @@ def main(variant: str = "baseline", quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.bench import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--variant", default="baseline")
     ap.add_argument("--quick", action="store_true")

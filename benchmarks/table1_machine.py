@@ -47,6 +47,8 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.bench import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     main(**vars(ap.parse_args()))

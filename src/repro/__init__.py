@@ -1,7 +1,1 @@
-"""repro — Arm-membench throughput benchmark, reproduced on the JAX/TPU stack.
-
-Importing any ``repro`` subpackage installs the jax forward-compat layer
-(see repro.compat): the codebase is written against the modern sharding API
-and runs unchanged on the pinned jax 0.4.x toolchain.
-"""
-from repro import compat as _compat  # noqa: F401  (side effect: installs shims)
+"""repro — Arm-membench throughput benchmark, reproduced on the JAX/TPU stack."""

@@ -342,6 +342,14 @@ class _MeshOracleBackend(_CaseBackend):
 
     def make_case(self, spec, mix, shape, dtype, passes):
         import jax
+        per_shard = self.per_shard_case(spec, mix, shape, dtype, passes)
+        return jax.jit(lambda *xs: per_shard(*xs).sum())
+
+    def per_shard_case(self, spec, mix, shape, dtype, passes):
+        """The mesh computation before its cross-shard sum: returns the
+        ``(devices,)`` vector of per-shard accumulators, which a check can
+        hold against the single-device oracle on each shard's slice."""
+        import jax
         from jax.sharding import PartitionSpec as P
         k = spec.devices
         rows, lanes = shape
@@ -390,7 +398,7 @@ class _MeshOracleBackend(_CaseBackend):
 
         @jax.jit
         def fn(*xs):
-            return smap(*(x.reshape(k, rows // k, lanes) for x in xs)).sum()
+            return smap(*(x.reshape(k, rows // k, lanes) for x in xs))
 
         return fn
 
@@ -444,7 +452,7 @@ class DistributedBackend(_MeshOracleBackend):
     * buffer placement: a host-built working set becomes a *global* array
       via ``jax.make_array_from_callback`` — each process materializes only
       its addressable shards on device (``device_put`` can't target
-      non-addressable shards on the pinned toolchain).  Companions computed
+      non-addressable shards).  Companions computed
       *from* the placed buffer (triad's ``x * 0.5``, the rw streams) are
       already global and pass through untouched.
     * process roles: every process runs the identical SPMD measurement loop
@@ -509,8 +517,9 @@ class DistributedBackend(_MeshOracleBackend):
 class PallasBackend(_CaseBackend):
     """The Pallas TPU kernels (kernels/membench) with explicit VMEM tiling.
 
-    interpret=True validates kernel-body semantics on CPU; on real TPU set
-    BenchSpec(interpret=False) for wall-clock-meaningful numbers.
+    The platform decides interpret mode (``membench.resolve_interpret``):
+    interpreted on the CPU, where only kernel-body semantics are meaningful,
+    and compiled on a TPU.
     """
     name = "pallas"
     DEFAULT_BLOCK_ROWS = 128
@@ -529,11 +538,20 @@ class PallasBackend(_CaseBackend):
         return r
 
     def validate(self, spec: BenchSpec) -> None:
+        from repro.kernels.membench.membench import resolve_interpret
+        compiled = not resolve_interpret()
         for m in spec.mixes:
             mix = get_mix(m)
             if not self.supports(mix):
                 raise BenchSpecError(f"mix {m!r} not supported on pallas"
                                      + _gate(self.name, "mix support"))
+            if mix.chase and compiled:
+                raise BenchSpecError(
+                    f"mix {m!r} has no compiled Pallas kernel: Mosaic cannot "
+                    f"lower the chase's dynamic_slice (R2 in ROADMAP.md); "
+                    f"run it on the xla backend"
+                    + _gate(self.name, "the chase kernel runs interpreted "
+                                       "only"))
             if spec.interleave > 1 and not interleavable(mix):
                 raise BenchSpecError(
                     f"mix {m!r} has no interleaved variant on pallas "
@@ -544,6 +562,7 @@ class PallasBackend(_CaseBackend):
 
     def make_case(self, spec, mix, shape, dtype, passes):
         from repro.kernels.membench import ops as mb_ops
+        from repro.kernels.membench.membench import resolve_interpret
         rows = self._resolve(spec, shape[0])
         if rows > shape[0] or shape[0] % rows:
             raise BenchSpecError(
@@ -561,12 +580,12 @@ class PallasBackend(_CaseBackend):
                 f"interleave {spec.interleave} does not divide the "
                 f"{rows}-row VMEM tile"
                 + _gate(self.name, "interleave | block_rows"))
+        interpret = resolve_interpret()
         trace.event("backend.dispatch", backend=self.name, mix=mix.name,
-                    block_rows=rows, interpret=spec.interpret,
-                    load=spec.load)
+                    block_rows=rows, interpret=interpret, load=spec.load)
         return mb_ops.make_timed_kernel(
             mix.name, depth=mix.fma_depth or 8, block_rows=rows,
-            streams=spec.streams, interpret=spec.interpret, passes=passes,
+            streams=spec.streams, interpret=interpret, passes=passes,
             unroll=spec.unroll, interleave=spec.interleave, load=spec.load)
 
     def abstract_args(self, spec, mix, shape, dtype):

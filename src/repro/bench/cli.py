@@ -290,8 +290,6 @@ def cmd_characterize(args) -> int:
         mixes = ("load_sum", "copy", "fma_8", "fma_32")
     if args.mixes:
         mixes = tuple(args.mixes.split(","))
-    if args.interpret is not None:
-        kw["spec_kw"] = {"interpret": args.interpret}
 
     model, sweep = characterize(mixes=mixes, primary=mixes[0], **kw)
     _obs_finish(args, sweep.result, "characterize")
@@ -601,8 +599,6 @@ def main(argv=None) -> int:
     p_chz.add_argument("--max-rounds", dest="max_rounds", type=int, default=8)
     p_chz.add_argument("--mixes", "--mix", default=None,
                        help="comma list; first is the detection-driving mix")
-    p_chz.add_argument("--interpret", type=lambda s: s.lower() != "false",
-                       default=None, help="Pallas interpret mode override")
     p_chz.add_argument("--compare", default=None,
                        help="documented spec to diff against (e.g. "
                             "fujitsu-a64fx, host)")

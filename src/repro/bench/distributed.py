@@ -82,9 +82,9 @@ def initialize(coordinator_address: str, num_processes: int,
                process_id: int) -> None:
     """``jax.distributed.initialize`` + the CPU-collectives knob.
 
-    The pinned toolchain's CPU backend refuses multi-process computations
-    unless a cross-process collective implementation is selected; gloo ships
-    in jaxlib, so forced-host-device simulation works out of the box.  Must
+    JAX's CPU backend refuses multi-process computations unless a
+    cross-process collective implementation is selected; gloo ships in
+    jaxlib, so forced-host-device simulation works out of the box.  Must
     run before the jax backend initializes.
     """
     global _initialized
@@ -92,10 +92,7 @@ def initialize(coordinator_address: str, num_processes: int,
     if os.environ.get("JAX_PLATFORMS", "cpu").startswith("cpu") or \
             "xla_force_host_platform_device_count" in \
             os.environ.get("XLA_FLAGS", ""):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass    # older/newer jaxlib without the knob: TPU/GPU don't need it
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -264,7 +261,9 @@ def launch_local(cmd: list[str], processes: int,
                  stream_to=None) -> int:
     """Spawn ``cmd`` as ``processes`` coordinated local processes.
 
-    Each child gets the REPRO_* coordination triple plus
+    Each child gets the REPRO_* coordination triple, ``JAX_PLATFORMS=cpu``
+    (this simulates several hosts on the CPU; a child that reached for the
+    accelerator would contend with its siblings for one chip) and
     ``--xla_force_host_platform_device_count=devices_per_process`` appended
     to ``XLA_FLAGS`` (appended last, so it wins over any count the command
     sets for its single-process path) — the global mesh the children see has
@@ -298,6 +297,7 @@ def launch_local(cmd: list[str], processes: int,
         # the coordination barrier
         for i in range(processes):
             child_env = dict(base,
+                             JAX_PLATFORMS="cpu",
                              XLA_FLAGS=xla_flags,
                              REPRO_COORDINATOR=f"127.0.0.1:{port}",
                              REPRO_NUM_PROCESSES=str(processes),

@@ -37,7 +37,9 @@ spec_version history: 1 = original knob set; 2 = adds ``devices`` (older
 files load with the single-device default); 3 = adds ``unroll`` /
 ``interleave`` (the instruction-stream knobs; older files load with 1/1);
 4 = adds ``load`` (co-scheduled bandwidth generators for loaded-latency
-composites; older files load with the idle default 0).
+composites; older files load with the idle default 0); 5 = drops
+``interpret`` (Pallas interpret mode follows the platform; the field in older
+files is ignored).
 """
 from __future__ import annotations
 
@@ -48,7 +50,7 @@ from pathlib import Path
 
 from repro.bench import mixes as mixreg
 
-SPEC_VERSION = 4
+SPEC_VERSION = 5
 
 
 class BenchSpecError(ValueError):
@@ -79,7 +81,6 @@ class BenchSpec:
     reps: int = 10
     warmup: int = 2
     value: float = 1.234567           # buffer init value (denormal-avoiding)
-    interpret: bool = True            # Pallas interpret mode (False on TPU)
     tags: tuple[str, ...] = ()        # free-form labels carried into results
 
     # -- validation ---------------------------------------------------------
@@ -180,6 +181,8 @@ class BenchSpec:
         if ver > SPEC_VERSION:
             raise BenchSpecError(
                 f"spec_version {ver} is newer than supported {SPEC_VERSION}")
+        if ver < 5:
+            d.pop("interpret", None)
         known = {f.name for f in dataclasses.fields(cls)}
         unknown = set(d) - known
         if unknown:

@@ -38,8 +38,7 @@ class TuneResult:
 
 
 def sweep_block_shapes(nbytes: int, mix: str = "load_sum", dtype=jnp.float32,
-                       reps: int = 8, interpret: bool = True,
-                       tune_unroll: bool = False, model=None,
+                       reps: int = 8, tune_unroll: bool = False, model=None,
                        ecm_keep: int | None = None,
                        runner=None) -> TuneResult:
     """Run the *Pallas* membench kernels across block shapes via the bench
@@ -59,8 +58,8 @@ def sweep_block_shapes(nbytes: int, mix: str = "load_sum", dtype=jnp.float32,
     timed; the pruned rows and their predictions land in ``TuneResult.ecm``
     so the saving is auditable, never silent.
 
-    interpret=True on CPU (kernel-body semantics validated); on real TPU pass
-    interpret=False for wall-clock-meaningful numbers.
+    The platform decides Pallas interpret mode (see ``bench.backends
+    .PallasBackend``): CPU timings validate structure only.
     """
     from repro.bench import BenchSpec, Runner
     from repro.core import buffers
@@ -83,7 +82,7 @@ def sweep_block_shapes(nbytes: int, mix: str = "load_sum", dtype=jnp.float32,
     for rows in candidates:
         spec = BenchSpec(mixes=(mix,), sizes=(nbytes,), dtype=dtype_s,
                          backend="pallas", block_rows=rows, passes=1,
-                         reps=reps, warmup=1, interpret=interpret)
+                         reps=reps, warmup=1)
         table[rows] = runner.run(spec).points[0].gbps
     best = max(table, key=table.get)
     best_unroll, unroll_table, unroll_audit = 1, None, None
@@ -104,8 +103,7 @@ def sweep_block_shapes(nbytes: int, mix: str = "load_sum", dtype=jnp.float32,
         for u in CANDIDATE_UNROLLS:
             spec = BenchSpec(mixes=(mix,), sizes=(nbytes,), dtype=dtype_s,
                              backend="pallas", block_rows=best, passes=u,
-                             unroll=u, reps=reps, warmup=1,
-                             interpret=interpret)
+                             unroll=u, reps=reps, warmup=1)
             unroll_table[u] = runner.run(spec).points[0].gbps
             unroll_audit[u] = waiver_reason(mixdef, "pallas", {"unroll": u})
         sound = [u for u in unroll_table if unroll_audit[u] is None]

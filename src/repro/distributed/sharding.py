@@ -149,8 +149,7 @@ class ShardCtx:
 
 
 def make_smoke_ctx() -> ShardCtx:
-    """1-device mesh with the production axis names (CPU tests).  On jax
-    0.4.x the AxisType/axis_types surface comes from repro.compat."""
+    """1-device mesh with the production axis names (CPU tests)."""
     mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
     return ShardCtx(mesh)

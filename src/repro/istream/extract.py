@@ -441,7 +441,9 @@ def computation_counts(module: HloModule, name: str,
                 counts.stores += full
     if virtual:
         # materialized root: the fusion's output buffer is written (a DUS
-        # root aliases its target in place — the update is already counted)
+        # root aliases its target in place — the update is already counted).
+        # A scalar root is loop state (a counter, a compare, a carried
+        # index), not a buffer — the same rule as region-level results.
         root = comp.instrs.get(comp.root)
         if root is not None:
             if root.opcode == "tuple":
@@ -454,10 +456,11 @@ def computation_counts(module: HloModule, name: str,
                             and src.opcode != "dynamic-update-slice"):
                         counts.stores += src.elems
                         seen.add(o)
-            elif (root.opcode not in FREE_OPS
+            elif (root.elems > 1
+                  and root.opcode not in FREE_OPS
                   and root.opcode not in CONTROL_OPS
                   and root.opcode != "dynamic-update-slice"):
-                counts.stores += max(root.elems, 1)
+                counts.stores += root.elems
     memo[key] = counts
     return counts
 

@@ -14,8 +14,11 @@ feeds the accumulator, so the measured time is pure data movement + grid
 overhead — the LD1/LD2D-only loop of §4.
 
 The grid accumulates into a (1, 1) output revisited every step; TPU grids are
-sequential per core, so the accumulation is race-free (and the revisited block
-stays resident in VMEM).
+sequential per core, so the accumulation is race-free.  The accumulator lives
+in SMEM: Mosaic refuses scalar stores to VMEM.
+
+Interpret mode follows the platform (``resolve_interpret``): the kernels run
+interpreted exactly on the CPU and compile everywhere else.
 """
 from __future__ import annotations
 
@@ -24,6 +27,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: the revisited scalar accumulator of the load-family and chase kernels
+_SCALAR_OUT = pl.BlockSpec((1, 1), lambda i: (0, 0),
+                           memory_space=pltpu.SMEM)
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode for the default JAX platform: ``None`` means
+    interpreted on the CPU and compiled on an accelerator.  Asking for the
+    interpreter on an accelerator is an error — its timings would be the
+    interpreter's, labelled with the device's name."""
+    platform = jax.default_backend()
+    if interpret is None:
+        return platform == "cpu"
+    if interpret and platform != "cpu":
+        raise ValueError(f"Pallas interpret mode requested on a {platform} "
+                         f"device; membench kernels compile there")
+    return interpret
 
 
 def _mix_body(mix: str, depth: int, blk, w=None, interleave: int = 1):
@@ -138,14 +160,16 @@ def _stream_index_map(streams: int, n_blocks: int):
 
 def membench_call(x, *, mix: str = "load_sum", depth: int = 8,
                   block_rows: int = 128, streams: int = 1,
-                  interpret: bool = True, y=None, ys=(),
+                  interpret: bool | None = None, y=None, ys=(),
                   interleave: int = 1):
     """x: (rows, 128) f32/bf16; returns scalar (load-family) or array (copy /
     triad) or tuple-of-arrays (rw family) output.  ``triad`` needs a second
     same-shape operand ``y``; ``rw_RtoW`` needs its R-1 extra read streams as
     ``ys`` and returns its W outputs as a tuple.  ``interleave`` splits each
     VMEM tile into independent row-chunk dependence chains (load_sum / copy /
-    rw only — the bench backend gates the rest)."""
+    rw only — the bench backend gates the rest).  ``interpret=None`` follows
+    the platform (``resolve_interpret``)."""
+    interpret = resolve_interpret(interpret)
     rows, lanes = x.shape
     assert rows % block_rows == 0, (rows, block_rows)
     n_blocks = rows // block_rows
@@ -190,7 +214,7 @@ def membench_call(x, *, mix: str = "load_sum", depth: int = 8,
             _chase_kernel,
             grid=(n_blocks,),
             in_specs=in_specs[:1],
-            out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            out_specs=_SCALAR_OUT,
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
             interpret=interpret,
         )(x)[0, 0]
@@ -222,7 +246,7 @@ def membench_call(x, *, mix: str = "load_sum", depth: int = 8,
         kern,
         grid=(n_blocks,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        out_specs=_SCALAR_OUT,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
     )(*operands)[0, 0]

@@ -19,13 +19,15 @@ def _split_mix(mix: str, depth: int) -> tuple[str, int]:
 
 
 def make_kernel(mix: str = "load_sum", depth: int = 8, block_rows: int = 128,
-                streams: int = 1, interpret: bool = True,
+                streams: int = 1, interpret: bool | None = None,
                 interleave: int = 1):
     """Returns jit'd fn(x) -> jax array (scalar or array output).
 
     ``triad`` returns fn(x, y) — two read streams, one write stream.
     ``interleave`` > 1 splits each VMEM tile into independent row-chunk
-    dependence chains (load_sum / copy / rw only).
+    dependence chains (load_sum / copy / rw only).  ``interpret=None``
+    follows the platform (``membench.resolve_interpret``); tests pass
+    ``True``/``False`` to steer it.
     """
     base_mix, depth_eff = _split_mix(mix, depth)
 
@@ -57,7 +59,7 @@ def make_kernel(mix: str = "load_sum", depth: int = 8, block_rows: int = 128,
 
 def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
                       block_rows: int = 128, streams: int = 1,
-                      interpret: bool = True, passes: int = 1,
+                      interpret: bool | None = None, passes: int = 1,
                       unroll: int = 1, interleave: int = 1,
                       load: int = 0):
     """Like make_kernel, but loops ``passes`` times over the buffer inside one

@@ -185,9 +185,7 @@ def analyze(compiled, model_flops: float | None = None,
     model's — pass the ``FittedMachineModel`` that ``repro.characterize``
     measured on this very machine, a documented ``HardwareSpec``, or a spec
     registry name; see ``machine_constants``."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per computation
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     colls = parse_collectives(compiled.as_text())

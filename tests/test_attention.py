@@ -3,11 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                     # optional dep; see pyproject [test]
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels.flash_attention.ref import reference as naive_attention
 from repro.models.attention import (apply_rope, chunked_attention,

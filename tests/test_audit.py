@@ -18,11 +18,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                     # optional dep; see pyproject [test]
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.audit import (EXIT_OK, EXIT_VIOLATION, audit_case, audit_goldens,
                          audit_hlo, audit_registry, ecm_filter_rows,

@@ -387,11 +387,14 @@ def test_cli_compare_prints_skipped(capsys):
 def test_spec_devices_roundtrip_and_v1_backcompat():
     s = BenchSpec(mixes=("load_sum",), backend="sharded", devices=1, **TINY)
     d = json.loads(s.to_json())
-    assert d["spec_version"] == 4 and d["devices"] == 1
+    assert d["spec_version"] == 5 and d["devices"] == 1
+    assert "interpret" not in d
     assert BenchSpec.from_dict(d) == s
     old = {k: v for k, v in d.items()
            if k not in ("devices", "unroll", "interleave")}  # a v1 spec file
     old["spec_version"] = 1
+    old["interpret"] = True     # retired in v5: the platform decides
+    assert BenchSpec.from_dict(old) == s
     assert BenchSpec.from_dict(old).devices == 1
     assert BenchSpec.from_dict(old).unroll == 1
     assert BenchSpec.from_dict(old).interleave == 1

@@ -119,6 +119,17 @@ def test_launch_local_propagates_worker_failure(tmp_path):
     assert rc == 3
 
 
+def test_launch_local_children_run_on_cpu():
+    """The launcher simulates hosts on the CPU: its children never reach for
+    the accelerator the parent may hold, whatever the parent's platform."""
+    check = ("import os, sys; "
+             "sys.exit(os.environ.get('JAX_PLATFORMS') != 'cpu')")
+    rc = dist.launch_local([sys.executable, "-c", check], processes=2,
+                           env=dict(os.environ, JAX_PLATFORMS="tpu"),
+                           timeout=60, stream_to=open(os.devnull, "w"))
+    assert rc == 0
+
+
 # ---------------------------------------------------------------------------
 # 2-process launcher end-to-end (subprocesses; one shared run)
 # ---------------------------------------------------------------------------

@@ -10,11 +10,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                     # optional dep; see pyproject [test]
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax.numpy as jnp
 

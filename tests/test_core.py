@@ -2,11 +2,8 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                     # optional dep; see pyproject [test]
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import analysis, buffers, instruction_mix, sweep, timing
 from repro.core.machine_model import TPU_V5E, HardwareSpec, MemLevel, detect_host

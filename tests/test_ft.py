@@ -8,11 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                     # optional dep; see pyproject [test]
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.checkpoint import checkpoint as ckpt
 from repro.ft.stragglers import StepTimer, probe_devices

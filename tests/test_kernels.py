@@ -48,6 +48,18 @@ def test_membench_stream_orders_equivalent():
     assert max(outs) - min(outs) < 1e-3
 
 
+def test_membench_interpret_follows_platform(monkeypatch):
+    """Interpreted exactly on the CPU; on an accelerator the kernels compile
+    and a request for the interpreter is refused."""
+    from repro.kernels.membench.membench import resolve_interpret
+    assert resolve_interpret() is True
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_interpret(True)
+
+
 def test_membench_work_accounting():
     x = working_set(32 * 1024)
     b, f = mb_ops.work_per_call("load_sum", x)

@@ -2,11 +2,8 @@
 import jax
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                     # optional dep; see pyproject [test]
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import ShardCtx

@@ -25,6 +25,7 @@ falls back to it, uncached, when ``make_case`` is absent.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Protocol, runtime_checkable
 
 import jax.numpy as jnp
@@ -56,6 +57,23 @@ def case_knobs(spec: BenchSpec) -> tuple:
     return tuple((f.name, getattr(spec, f.name))
                  for f in dataclasses.fields(spec)
                  if f.name not in _NON_CASE_FIELDS)
+
+
+def _dispatched(case: Callable, backend: str, mix: str) -> Callable:
+    """``case`` behind the ``case.dispatch`` span, which covers each call
+    from Python entry to the enqueue of its program (the caller's
+    ``block_until_ready`` lies outside).  With tracing off it calls the case
+    directly and never touches ``span()``.  It keeps the case's name, so
+    ``jax.jit`` of it names the module as the case does."""
+    tracer = trace.get_tracer()
+
+    @functools.wraps(case)
+    def dispatch(*bufs):
+        if not tracer.enabled:
+            return case(*bufs)
+        with tracer.span("case.dispatch", backend=backend, mix=mix):
+            return case(*bufs)
+    return dispatch
 
 
 def _gate(backend_name: str, rule: str) -> str:
@@ -279,9 +297,8 @@ class XLABackend(_CaseBackend):
         _validate_oracle_knobs(spec, self.name)
 
     def make_case(self, spec, mix, shape, dtype, passes):
-        trace.event("backend.dispatch", backend=self.name, mix=mix.name,
-                    load=spec.load)
-        return _oracle_case(spec, mix, shape[0], passes, self.name)
+        return _dispatched(_oracle_case(spec, mix, shape[0], passes,
+                                        self.name), self.name, mix.name)
 
     def bind_case(self, case, spec, mix, x):
         return _bind_oracle_case(case, mix, x, load=spec.load)
@@ -343,7 +360,8 @@ class _MeshOracleBackend(_CaseBackend):
     def make_case(self, spec, mix, shape, dtype, passes):
         import jax
         per_shard = self.per_shard_case(spec, mix, shape, dtype, passes)
-        return jax.jit(lambda *xs: per_shard(*xs).sum())
+        return _dispatched(jax.jit(lambda *xs: per_shard(*xs).sum()),
+                           self.name, mix.name)
 
     def per_shard_case(self, spec, mix, shape, dtype, passes):
         """The mesh computation before its cross-shard sum: returns the
@@ -357,11 +375,6 @@ class _MeshOracleBackend(_CaseBackend):
             raise BenchSpecError(
                 f"devices={k} does not divide the {rows}-row working set")
         mesh = self._mesh(k)
-        # dispatch provenance: which backend, what mesh shape, and whether a
-        # generator co-schedule is composed in (the loaded-latency split)
-        trace.event("backend.dispatch", backend=self.name, mix=mix.name,
-                    mesh_shape=[k], load=spec.load,
-                    composite=bool(mix.chase and spec.load))
         n_args = _mix_arity(mix, spec.load)   # triad: (a,b,c); rw: R+W
 
         if mix.chase and spec.load:
@@ -580,13 +593,11 @@ class PallasBackend(_CaseBackend):
                 f"interleave {spec.interleave} does not divide the "
                 f"{rows}-row VMEM tile"
                 + _gate(self.name, "interleave | block_rows"))
-        interpret = resolve_interpret()
-        trace.event("backend.dispatch", backend=self.name, mix=mix.name,
-                    block_rows=rows, interpret=interpret, load=spec.load)
-        return mb_ops.make_timed_kernel(
+        return _dispatched(mb_ops.make_timed_kernel(
             mix.name, depth=mix.fma_depth or 8, block_rows=rows,
-            streams=spec.streams, interpret=interpret, passes=passes,
-            unroll=spec.unroll, interleave=spec.interleave, load=spec.load)
+            streams=spec.streams, interpret=resolve_interpret(),
+            passes=passes, unroll=spec.unroll, interleave=spec.interleave,
+            load=spec.load), self.name, mix.name)
 
     def abstract_args(self, spec, mix, shape, dtype):
         import jax

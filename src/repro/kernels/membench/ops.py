@@ -18,6 +18,16 @@ def _split_mix(mix: str, depth: int) -> tuple[str, int]:
     return mix, depth
 
 
+def _jit_named(name: str):
+    """``jax.jit`` under a stable ``name``: the compiled module reads
+    ``jit_<name>`` and a call inlined from it keeps the name, so a profiler
+    trace finds the program by it."""
+    def jit(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
+    return jit
+
+
 def make_kernel(mix: str = "load_sum", depth: int = 8, block_rows: int = 128,
                 streams: int = 1, interpret: bool | None = None,
                 interleave: int = 1):
@@ -27,12 +37,14 @@ def make_kernel(mix: str = "load_sum", depth: int = 8, block_rows: int = 128,
     ``interleave`` > 1 splits each VMEM tile into independent row-chunk
     dependence chains (load_sum / copy / rw only).  ``interpret=None``
     follows the platform (``membench.resolve_interpret``); tests pass
-    ``True``/``False`` to steer it.
+    ``True``/``False`` to steer it.  The jitted function is named
+    ``membench_<mix>``.
     """
     base_mix, depth_eff = _split_mix(mix, depth)
+    named = _jit_named(f"membench_{mix}")
 
     if base_mix == "triad":
-        @jax.jit
+        @named
         def fn2(x, y):
             return membench_call(x, mix="triad", depth=depth_eff,
                                  block_rows=block_rows, streams=streams,
@@ -40,7 +52,7 @@ def make_kernel(mix: str = "load_sum", depth: int = 8, block_rows: int = 128,
         return fn2
 
     if mix.startswith("rw_"):
-        @jax.jit
+        @named
         def fnr(x, *ys):
             return membench_call(x, mix=mix, depth=depth_eff,
                                  block_rows=block_rows, streams=streams,
@@ -48,7 +60,7 @@ def make_kernel(mix: str = "load_sum", depth: int = 8, block_rows: int = 128,
                                  interleave=interleave)
         return fnr
 
-    @jax.jit
+    @named
     def fn(x):
         return membench_call(x, mix=base_mix, depth=depth_eff,
                              block_rows=block_rows, streams=streams,
@@ -69,7 +81,8 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
     the XLA oracles).  ``unroll`` runs that many chained kernel sweeps per
     loop trip (``core.instruction_mix._pass_loop`` — the same unroll
     discipline as the oracles, so accounting parity holds by construction).
-    Always returns a scalar fn — fn(x), or fn(x, y) for ``triad``.
+    Always returns a scalar fn — fn(x), or fn(x, y) for ``triad`` — named
+    ``membench_passloop_<mix>``.
 
     Mixes whose kernel produces array outputs (copy / triad / rw) loop-carry
     those outputs through the pass loop with ROTATING per-sweep slots
@@ -93,6 +106,7 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
     from repro.core.instruction_mix import (_consume_slots, _pass_loop,
                                             _rotating_pass_loop)
     base_mix, _ = _split_mix(mix, depth)
+    named = _jit_named(f"membench_passloop_{mix}")
     one = make_kernel(mix, depth=depth, block_rows=block_rows,
                       streams=streams, interpret=interpret,
                       interleave=interleave)
@@ -131,19 +145,19 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
         return _consume_slots(acc, slots)
 
     if base_mix == "triad":
-        @jax.jit
+        @named
         def fn2(x, y):
             return _carried(one, x, (y,))
         return fn2
 
     if mix.startswith("rw_"):
-        @jax.jit
+        @named
         def fnr(x, *ys):
             return _carried(one, x, ys)
         return fnr
 
     if base_mix == "copy":
-        @jax.jit
+        @named
         def fnc(x):
             return _carried(one, x, ())
         return fnc
@@ -154,7 +168,7 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
                               streams=streams, interpret=interpret)
         sweeps = load * GEN_SWEEPS_PER_PASS
 
-        @jax.jit
+        @named
         def fnl(x, g):         # x: int32 perm buffer; g: generator buffer
             def gsweep(_, c):
                 g, acc = c
@@ -175,7 +189,7 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
 
         return fnl
 
-    @jax.jit
+    @named
     def fn(x):                 # scalar-output mixes: nothing to narrow
         def body(_, carry):
             x, acc = carry

@@ -21,10 +21,10 @@ mistakes the paper warns against.  This tracer is deliberately tiny:
 
 Span taxonomy (see ``bench/README.md`` → Observability for the full map):
 ``runner.run`` > ``runner.plan`` / ``runner.size`` > ``case.build`` /
-``buffers.build`` / ``runner.case`` > ``timing.warmup`` / ``timing.rep``;
-``backend.<name>.make_case`` under the plan; ``launch.child`` and
-``characterize.round`` at top level in their own processes.  Instant
-events: ``cache`` (hit/miss), ``buffers.release``,
+``buffers.build`` / ``runner.case`` > ``timing.warmup`` / ``timing.rep`` >
+``case.dispatch`` (every call of a backend's compiled case, up to the
+enqueue); ``launch.child`` and ``characterize.round`` at top level in their
+own processes.  Instant events: ``cache`` (hit/miss), ``buffers.release``,
 ``launch.straggler_kill``, ``characterize.bisect``.
 
 Export formats:
@@ -38,11 +38,19 @@ Export formats:
 Timestamps are microseconds relative to the tracer's epoch
 (``perf_counter_ns`` at construction/``clear``); the wall-clock anchor of
 the epoch is kept in the metadata so separate traces can be aligned.
+
+Profiler bridge: once JAX is imported, every enabled span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, with the span's args as
+its metadata.  While a JAX profiler trace runs, the span then lands on its
+thread's line of the host plane, on the device trace's clock, so an idle
+gap of the device can be named after the span the host was in.  Instant
+events stay in memory only.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from pathlib import Path
@@ -68,9 +76,23 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+#: ``jax.profiler.TraceAnnotation``, looked up by the first enabled span
+#: that finds JAX imported (this module never imports JAX itself)
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
 class _Span:
-    """One live span: records a single ``"X"`` complete event on exit."""
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_depth")
+    """One live span: records a single ``"X"`` complete event on exit, and
+    mirrors itself into the JAX profiler's trace (see the module doc)."""
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -82,11 +104,18 @@ class _Span:
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        annotation = _annotation()
+        self._ann = None
+        if annotation is not None:
+            self._ann = annotation(self.name, **self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         # balance even if an inner span leaked (never happens with `with`,
         # but a trace must not corrupt on someone's manual __enter__)

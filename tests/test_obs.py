@@ -76,10 +76,13 @@ def test_disabled_tracer_is_allocation_free_noop():
 
 def test_timed_path_never_touches_spans_when_disabled(monkeypatch):
     """The zero-overhead guarantee: with tracing off, ``time_fn`` must run
-    the original untraced loop — a span() call anywhere in it would raise
-    here."""
+    the original untraced loop, and a backend's compiled case must call
+    straight through its ``case.dispatch`` wrapper — a span() call anywhere
+    in either would raise here."""
     import jax.numpy as jnp
 
+    from repro.bench.backends import get_backend
+    from repro.bench.mixes import get_mix
     from repro.core import timing
 
     def explode(*a, **k):
@@ -91,6 +94,73 @@ def test_timed_path_never_touches_spans_when_disabled(monkeypatch):
     x = jnp.ones((8, 8))
     t = timing.time_fn(lambda: x + 1, reps=3, warmup=1, bytes_per_call=1.0)
     assert len(t.times_s) == 3
+    spec = BenchSpec(mixes=("load_sum",), sizes=(64 * 2**10,), passes=2)
+    mix = get_mix("load_sum")
+    backend = get_backend("xla")
+    buf = jnp.ones((128, 128), jnp.float32)
+    case = backend.make_case(spec, mix, buf.shape, buf.dtype, 2)
+    fn = backend.bind_case(case, spec, mix, buf)
+    t = timing.time_fn(fn, reps=3, warmup=1, bytes_per_call=1.0)
+    assert len(t.times_s) == 3
+
+
+def test_spans_land_on_the_profiler_trace(tmp_path):
+    """With the tracer on under a JAX profiler trace, the spans of a bound
+    pallas and xla case and of one ``Runner.run`` are written to the host
+    line that holds the caller's own ``TraceAnnotation``, inside it, with
+    their args as metadata: the device trace's clock names them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    from repro.bench.backends import get_backend
+    from repro.bench.mixes import get_mix
+
+    mix = get_mix("load_sum")
+    buf = jnp.ones((128, 128), jnp.float32)
+    calls = []
+    for name in ("pallas", "xla"):
+        spec = BenchSpec(mixes=("load_sum",), sizes=(64 * 2**10,),
+                         backend=name, passes=2)
+        backend = get_backend(name)
+        case = backend.make_case(spec, mix, buf.shape, buf.dtype, 2)
+        calls.append(backend.bind_case(case, spec, mix, buf))
+    for fn in calls:
+        jax.block_until_ready(fn())         # compiled before the trace
+    run_spec = BenchSpec(mixes=("copy",), sizes=(64 * 2**10,), passes=2,
+                         reps=2, warmup=1)
+    trace.configure(enabled=True, clear=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("test.window"):
+            for fn in calls:
+                jax.block_until_ready(fn())
+            Runner().run(run_spec)
+    finally:
+        jax.profiler.stop_trace()
+        trace.configure(enabled=False)
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    lines = [list(line.events)
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    (line,) = [evs for evs in lines
+               if any(ev.name == "test.window" for ev in evs)]
+    (lo, hi) = [(ev.start_ns, ev.end_ns) for ev in line
+                if ev.name == "test.window"][0]
+    spans = {}
+    for ev in line:
+        if ev.name in ("case.dispatch", "runner.run", "buffers.build"):
+            assert lo <= ev.start_ns <= ev.end_ns <= hi, ev.name
+            stats = dict(ev.stats) if ev.name == "case.dispatch" else {}
+            spans.setdefault(ev.name, []).append(
+                (ev.start_ns, ev.end_ns, stats))
+    assert len(spans["runner.run"]) == len(spans["buffers.build"]) == 1
+    # two direct calls + the Runner's warm-up and reps, each dispatched
+    dispatch = spans["case.dispatch"]
+    assert len(dispatch) == 2 + run_spec.warmup + run_spec.reps
+    assert {d[2]["backend"] for d in dispatch} == {"pallas", "xla"}
+    (run_lo, run_hi, _), = spans["runner.run"]
+    assert sum(run_lo <= s <= e <= run_hi for s, e, _ in dispatch) == 3
 
 
 def test_timing_samples_bounded():
@@ -195,7 +265,7 @@ def test_traced_run_chrome_valid_and_covered(traced_run):
     names = {e["name"] for e in events}
     assert {"runner.run", "runner.plan", "runner.size", "buffers.build",
             "runner.case", "timing.warmup", "timing.rep", "case.build",
-            "cache", "backend.dispatch", "buffers.release"} <= names
+            "cache", "case.dispatch", "buffers.release"} <= names
 
 
 def test_obs_counters_match_trace_events(traced_run):

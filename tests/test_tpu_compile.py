@@ -5,6 +5,7 @@ is what the chip's compiler accepts or refuses; nothing runs and nothing is
 measured.  The topology is described inside a fixture, never at import: only
 one process at a time may load the TPU library."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,8 +74,14 @@ def _compile(backend, name, sharding):
 
 @pytest.mark.parametrize("name", PALLAS_MIXES)
 def test_pallas_mix_compiles_for_v5e(name, one_chip, on_tpu):
+    """It compiles, under the names a profiler trace of the chip shows: the
+    pass loop's module ``jit_membench_passloop_<mix>`` and the kernel's
+    custom call ``%membench_<mix>.N``."""
     hlo = _compile(get_backend("pallas"), name, one_chip)
-    assert "tpu_custom_call" in hlo, name
+    assert hlo.startswith(f"HloModule jit_membench_passloop_{name},"), \
+        hlo.splitlines()[0]
+    assert re.search(rf'%membench_{name}\.\d+ = .*'
+                     rf'custom_call_target="tpu_custom_call"', hlo), name
 
 
 @pytest.mark.parametrize("name", ["copy", "triad", "load_sum"])

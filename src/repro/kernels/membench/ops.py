@@ -8,7 +8,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.membench.membench import membench_call
+from repro.kernels.membench.membench import membench_call, resolve_interpret
+from repro.obs import metrics
 
 
 def _split_mix(mix: str, depth: int) -> tuple[str, int]:
@@ -76,11 +77,23 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
                       load: int = 0):
     """Like make_kernel, but loops ``passes`` times over the buffer inside one
     compiled call (the paper's measurement loop) so dispatch overhead does not
-    swamp cache-resident working sets.  A one-element self-dependent
-    perturbation chains the iterations (defeats loop-invariant hoisting, as in
-    the XLA oracles).  ``unroll`` runs that many chained kernel sweeps per
-    loop trip (``core.instruction_mix._pass_loop`` — the same unroll
-    discipline as the oracles, so accounting parity holds by construction).
+    swamp cache-resident working sets.  Each sweep depends on the one before
+    it, so XLA can neither hoist the kernel out of the loop nor merge two
+    sweeps.  How that chain is made follows what the kernel is
+    (``resolve_interpret``), and each build counts which in
+    ``metrics.REGISTRY``:
+
+    * ``passloop_chain_write``: a one-element self-dependent write into the
+      working set (as in the XLA oracles).  Scalar-output mixes, the
+      loaded chase, and every mix whose kernel is interpreted.
+    * ``passloop_chain_barrier``: array-output mixes with a compiled kernel
+      pass ``(x, extra, acc)`` through ``jax.lax.optimization_barrier``
+      before each sweep and write nothing, so the working set is read-only
+      loop state that XLA neither copies at entry nor writes per pass.
+
+    ``unroll`` runs that many chained kernel sweeps per loop trip
+    (``core.instruction_mix._pass_loop`` — the same unroll discipline as the
+    oracles, so accounting parity holds by construction).
     Always returns a scalar fn — fn(x), or fn(x, y) for ``triad`` — named
     ``membench_passloop_<mix>``.
 
@@ -95,7 +108,9 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
     (the dead-interior-sweep finding,
     ``tests/data/hlo/dead_sweep_xla_copy_u4.txt``).  On real TPU the opaque
     pallas_call never had either hazard, and the slots only alias the output
-    buffers the kernel writes anyway.
+    buffers the kernel writes anyway.  The compiled pass loop's HLO (no
+    entry copy, ``unroll`` kernel calls a trip) is pinned by
+    ``tests/test_tpu_compile.py``; ``repro.audit`` sees the interpreted one.
 
     ``load`` > 0 (``latency_chase`` only — the bench spec gates it) builds
     the loaded-latency composite fn(perm, gen): each probe pass is followed
@@ -107,13 +122,21 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
                                             _rotating_pass_loop)
     base_mix, _ = _split_mix(mix, depth)
     named = _jit_named(f"membench_passloop_{mix}")
+    interpret = resolve_interpret(interpret)
+    array_out = base_mix in ("copy", "triad") or mix.startswith("rw_")
+    barrier = array_out and not interpret
+    metrics.REGISTRY.inc("passloop_chain_barrier" if barrier
+                         else "passloop_chain_write")
     one = make_kernel(mix, depth=depth, block_rows=block_rows,
                       streams=streams, interpret=interpret,
                       interleave=interleave)
 
-    def _chain(x, r, acc):
+    def _fold(r, acc):
         val = r if getattr(r, "ndim", 0) == 0 else r.reshape(-1)[0]
-        acc = acc + val.astype(jnp.float32)
+        return acc + val.astype(jnp.float32)
+
+    def _chain(x, r, acc):
+        acc = _fold(r, acc)
         eps = (acc * 1e-30).astype(x.dtype).reshape(())
         return x.at[(0,) * x.ndim].add(eps), acc
 
@@ -123,14 +146,29 @@ def make_timed_kernel(mix: str = "load_sum", depth: int = 8,
 
     def _carried(call, x, extra):
         """Pass loop with the kernel outputs in rotating per-sweep carry
-        slots — every unrolled sweep's outputs stay live loop state (the
-        liveness mechanism; an ``optimization_barrier`` here demonstrably
-        does NOT survive XLA:CPU optimization)."""
+        slots — every unrolled sweep's outputs stay live loop state.
+
+        The chain between sweeps: a compiled kernel is an opaque custom call
+        that XLA cannot look into, so an ``optimization_barrier`` that ties
+        its operands to the previous sweep's ``acc`` is enough to keep each
+        call in the loop and apart from the others, and the working set is
+        never written (nor copied into the loop state first).  An
+        interpreted kernel is plain HLO: XLA:CPU drops the barrier and
+        merges the sweeps (the audit then counts half the work), so there
+        each sweep writes one element of every read stream and the kernel
+        reads distinct data."""
         out0 = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                             jax.eval_shape(call, x, *extra))
 
         def sweep(_, state, _outs):
             x, extra, acc = state
+            if barrier:
+                x_k, extra_k, acc = jax.lax.optimization_barrier(
+                    (x, extra, acc))
+                outs = call(x_k, *extra_k)
+                for o in jax.tree.leaves(outs):
+                    acc = _fold(o, acc)
+                return (x, extra, acc), outs
             outs = call(x, *extra)
             for o in jax.tree.leaves(outs):
                 x, acc = _chain(x, o, acc)

@@ -1,9 +1,9 @@
 """Counter/gauge registry — the numbers the trace's events add up to.
 
 Stdlib-only and always on: increments are dict operations under one lock,
-all of them on setup/teardown paths (plan, buffer build/release, cache
-lookup, launcher supervision) — never inside the timed repetition loop, so
-the measurement discipline is untouched.
+all of them on setup/teardown paths (plan, case build, buffer
+build/release, cache lookup, launcher supervision) — never inside the timed
+repetition loop, so the measurement discipline is untouched.
 
 Canonical counter names (what ``BenchResult.meta["obs"]`` carries — the
 set is open, these are the ones the built-in instrumentation emits):
@@ -14,6 +14,10 @@ set is open, these are the ones the built-in instrumentation emits):
     straggler_kills                launcher processes killed after a peer
                                    failure or timeout
     adaptive_rounds                characterize refinement rounds driven
+    passloop_chain_barrier /       Pallas pass loops built whose sweeps
+    passloop_chain_write           chain through an optimization barrier
+                                   (compiled array mixes) or a one-element
+                                   write into the working set (the rest)
 
 Gauges:
 

@@ -56,20 +56,58 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _compile(backend, name, sharding):
-    spec = BenchSpec(mixes=(name,), sizes=(NBYTES,), backend=backend.name,
-                     passes=1)
+def _compiled(backend, name, sharding, nbytes=NBYTES, passes=1, unroll=1):
+    spec = BenchSpec(mixes=(name,), sizes=(nbytes,), backend=backend.name,
+                     passes=passes, unroll=unroll)
     mix = get_mix(name)
+    shape = working_set_shape(nbytes)
     backend.validate(spec)
-    case = backend.make_case(spec, mix, SHAPE, jnp.float32, 1)
+    case = backend.make_case(spec, mix, shape, jnp.float32, passes)
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
-            for a in backend.abstract_args(spec, mix, SHAPE, jnp.float32)]
+            for a in backend.abstract_args(spec, mix, shape, jnp.float32)]
     compiled = jax.jit(case).lower(*args).compile()
     ma = compiled.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes)
     assert used < HBM_BYTES, (name, used)
-    return compiled.as_text()
+    return compiled
+
+
+def _compile(backend, name, sharding):
+    return _compiled(backend, name, sharding).as_text()
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """The instruction lines of each computation of a module's HLO text,
+    by name; the entry computation is also under ``"ENTRY"``."""
+    comps: dict[str, list[str]] = {}
+    lines = None
+    for line in hlo.splitlines():
+        m = re.match(r"(ENTRY )?%(\S+) \(", line)
+        if m:
+            lines = comps.setdefault(m.group(2), [])
+            if m.group(1):
+                comps["ENTRY"] = lines
+        elif line == "}":
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def _while_body(comps: dict[str, list[str]]) -> list[str]:
+    """The body of the pass loop: the one ``while`` of the entry."""
+    (body,) = [m.group(1) for line in comps["ENTRY"]
+               for m in [re.search(r" while\(.*body=%(\S+?)[,\s]", line)]
+               if m]
+    return comps[body]
+
+
+def _defines(lines: list[str], pattern: str) -> list[str]:
+    """The instructions among ``lines`` whose result and op match
+    ``pattern`` (``<shape>{<layout>} <op>(``)."""
+    return [line for line in lines
+            if re.match(rf"\s*(ROOT )?%\S+ = {pattern}", line)]
 
 
 @pytest.mark.parametrize("name", PALLAS_MIXES)
@@ -96,3 +134,44 @@ def test_pallas_chase_refused_on_tpu(on_tpu):
     spec = BenchSpec(mixes=("latency_chase",), backend="pallas")
     with pytest.raises(BenchSpecError, match="R2"):
         get_backend("pallas").validate(spec)
+
+
+WS = rf"f32\[{SHAPE[0]},{SHAPE[1]}\]"      # a working set's shape in HLO
+
+
+@pytest.mark.parametrize("unroll", [1, 2, 4])
+@pytest.mark.parametrize("name", ["copy", "triad", "rw_2to1"])
+def test_compiled_array_passloop_reads_working_set_in_place(
+        name, unroll, one_chip, on_tpu):
+    """A compiled kernel's sweeps are chained through an optimization
+    barrier, not a write into the working set: the entry makes no copy of
+    any working set for the loop to write, the loop body runs ``unroll``
+    kernel calls a trip (none hoisted, none merged), and the temporaries
+    are the rotating output slots alone.  ``passes`` is two trips at every
+    ``unroll``: XLA inlines a loop of one trip, leaving no body to read."""
+    compiled = _compiled(get_backend("pallas"), name, one_chip,
+                         passes=2 * unroll, unroll=unroll)
+    comps = _computations(compiled.as_text())
+    assert not _defines(comps["ENTRY"], rf"{WS}\S* copy(-start)?\("), name
+    kernels = _defines(_while_body(comps),
+                       rf"{WS}\S* custom-call\(.*\), "
+                       rf'custom_call_target="tpu_custom_call"')
+    assert len(kernels) == unroll, (name, unroll, len(kernels))
+    assert all(f"%membench_{name}." in k.split("=")[0] for k in kernels)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= unroll * NBYTES + 2**20, (name, unroll, temp)
+
+
+def test_compiled_scalar_passloop_still_writes_working_set(one_chip, on_tpu):
+    """The scalar path keeps its one-element write into the carried
+    buffer: at 1 MiB ``load_sum``'s pass loop stages the working set in
+    VMEM (memory space ``S(1)``) and updates it there every pass."""
+    nbytes = 2**20
+    rows, cols = working_set_shape(nbytes)
+    hlo = _compiled(get_backend("pallas"), "load_sum", one_chip,
+                    nbytes=nbytes, passes=4).as_text()
+    comps = _computations(hlo)
+    vmem = rf"f32\[{rows},{cols}\]\{{1,0:T\(8,128\)S\(1\)\}}"
+    assert len(_defines(_while_body(comps),
+                        rf"{vmem} dynamic-update-slice\(")) == 1
+    assert _defines(comps["ENTRY"], rf"{vmem} copy-done\(")

@@ -149,9 +149,14 @@ class _CaseBackend:
 
 def _validate_oracle_knobs(spec: BenchSpec, backend_name: str) -> None:
     """Knob rules of the core.instruction_mix oracles (shared by the xla
-    backend and the sharded backend, which runs the same kernels per shard)."""
+    backend and the sharded backend, which runs the same kernels per shard).
+    A collective mix that the backend supports has rules of its own
+    (``_validate_collective_knobs``)."""
     for m in spec.mixes:
         mix = get_mix(m)
+        if mix.collective and mix.supports(backend_name):
+            _validate_collective_knobs(spec, mix, backend_name)
+            continue
         if "xla" not in mix.backends:
             raise BenchSpecError(f"mix {m!r} not supported on {backend_name}"
                                  + _gate(backend_name, "mix support"))
@@ -184,6 +189,19 @@ def _validate_oracle_knobs(spec: BenchSpec, backend_name: str) -> None:
             f"streams>1 or block_rows (the interleaved oracles walk the "
             f"whole buffer in row chunks)"
             + _gate(backend_name, "interleave xor streams/block_rows"))
+
+
+def _validate_collective_knobs(spec: BenchSpec, mix: MixDef,
+                               backend_name: str) -> None:
+    """A collective exchanges each rank's whole shard: the oracles' walk
+    knobs (streams, block_rows, interleave) have no meaning for it."""
+    for knob, default in (("streams", 1), ("block_rows", None),
+                          ("interleave", 1)):
+        if getattr(spec, knob) != default:
+            raise BenchSpecError(
+                f"collective mix {mix.name!r} exchanges whole shards: "
+                f"{knob}={getattr(spec, knob)} has no meaning for it"
+                + _gate(backend_name, "collectives take no walk knobs"))
 
 
 def _mix_arity(mix: MixDef, load: int = 0) -> int:
@@ -359,9 +377,30 @@ class _MeshOracleBackend(_CaseBackend):
 
     def make_case(self, spec, mix, shape, dtype, passes):
         import jax
+        if mix.collective:
+            return _dispatched(self.collective_case(spec, mix, shape, passes),
+                               self.name, mix.name)
         per_shard = self.per_shard_case(spec, mix, shape, dtype, passes)
         return _dispatched(jax.jit(lambda *xs: per_shard(*xs).sum()),
                            self.name, mix.name)
+
+    def collective_case(self, spec, mix, shape, passes):
+        """A collective mix's timed program: ``passes`` exchanges of every
+        rank's shard a call (``core.collective_bench.make_passloop``),
+        returning the ``(devices,)`` vector of per-rank accumulators: no
+        cross-rank sum after the loop, so the exchanges are the call's
+        only collectives."""
+        from repro.core.collective_bench import make_passloop
+        k = spec.devices
+        if shape[0] % k:
+            raise BenchSpecError(
+                f"devices={k} does not divide the {shape[0]}-row working set")
+        if passes % spec.unroll:
+            raise BenchSpecError(
+                f"passes={passes} is not a multiple of unroll={spec.unroll}"
+                + _gate(self.name, "passes % unroll == 0"))
+        return make_passloop(self._mesh(k), mix.collective, passes,
+                             spec.unroll)
 
     def per_shard_case(self, spec, mix, shape, dtype, passes):
         """The mesh computation before its cross-shard sum: returns the

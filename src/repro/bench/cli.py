@@ -37,7 +37,7 @@ import json
 import os
 import sys
 
-from repro.bench.mixes import registry
+from repro.bench.mixes import get_mix
 from repro.bench.runner import Runner
 from repro.bench.spec import BenchSpec, BenchSpecError, quick_spec
 from repro.obs import ledger, trace
@@ -200,12 +200,12 @@ def cmd_run(args) -> int:
 
 
 def cmd_list_mixes(args) -> int:
-    from repro.bench.mixes import MAX_RW, mix_names
-    reg = registry()
+    from repro.bench.mixes import MAX_RW, collective_names, mix_names
     print(f"{'mix':10s} {'flops/elem':>10s} {'reads':>6s} {'writes':>6s}  "
           f"{'backends':16s} description")
-    for name in mix_names():     # deterministic: family parameter, then name
-        m = reg[name]
+    # deterministic: family parameter, then name; the collectives last
+    for name in mix_names() + collective_names():
+        m = get_mix(name)
         print(f"{name:10s} {m.flops_per_elem:10.1f} {m.reads_per_elem:6.1f} "
               f"{m.writes_per_elem:6.1f}  {'+'.join(m.backends):16s} "
               f"{m.description}")

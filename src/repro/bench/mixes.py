@@ -26,12 +26,27 @@ so the two backends can never disagree about what a measurement means.
                                    bandwidth; the probe half of the
                                    Mess-style loaded-latency composite
                                    (``BenchSpec.load`` adds generators)
+    ``all_reduce``     0           collective: every rank's shard summed and
+                                   left on every rank (OSU ``osu_allreduce``),
+                                   the interconnect analogue of NUMA-remote
+                                   access; ``sharded`` backend only
 
 The ``rw_RtoW`` family is generated by one shared formula (``rw_ratio``):
 ``bytes_per_pass = (R + W) * nbytes`` and ``flops_per_pass = 2(R-1) * n`` —
 the (R-1) mul+add pairs that fold R read streams into one value.  The
 registry lists a canonical ladder (``RW_RATIOS``); any ``rw_RtoW`` with
 R, W in 1..``MAX_RW`` resolves through ``get_mix``, like the fma family.
+
+A collective mix (``all_reduce``) moves bytes that depend on the group
+size k, not on per-element constants.  ``sizes`` is the global working set,
+and each of the k mesh shards is one rank's message of S = nbytes / k:
+``bytes_per_pass(nbytes, k)`` is that payload S (nccl-tests' algbw
+numerator) and ``bus_bytes_per_pass(nbytes, k)`` the least any algorithm
+sends per rank, ``BUS_FACTORS[op](k) * S`` (the busbw numerator; 2(k-1)/k
+for an all-reduce).  It needs a mesh, so it runs on the ``sharded``
+backend alone (no alias to the single-device oracles).  ``registry()`` and
+``mix_names()`` list the instruction mixes; ``collective_names()`` lists
+the collectives, and ``get_mix`` resolves both.
 
 Consumers: ``repro.bench.backends`` (kernel dispatch), ``repro.bench.runner``
 (work accounting), ``repro.core.instruction_mix`` (legacy ``mixes()`` view),
@@ -78,6 +93,10 @@ RW_COMBINE_COEF = 1.5
 #: declared ONCE here so they cannot disagree.
 GEN_SWEEPS_PER_PASS = 16
 
+#: collective -> bytes each rank must send per byte of its payload in a group
+#: of k ranks (nccl-tests ``doc/PERFORMANCE.md``, the bus-bandwidth factor)
+BUS_FACTORS = {"all_reduce": lambda k: 2.0 * (k - 1) / k}
+
 # execution aliases: the sharded/distributed backends run the xla oracles per
 # shard, so a mix runnable on xla is runnable on both (same kernels, same
 # accounting)
@@ -95,15 +114,31 @@ class MixDef:
     fma_depth: int = 0             # chain depth for the fma family
     rw: tuple[int, int] | None = None   # (reads, writes) for the rw family
     chase: bool = False            # dependent-chain latency probe?
+    collective: str = ""           # the exchange a collective mix times
     description: str = ""
 
-    def bytes_per_pass(self, nbytes: int) -> float:
+    def bytes_per_pass(self, nbytes: int, devices: int = 1) -> float:
+        """Declared bytes of one pass over a working set of ``nbytes``;
+        for a collective, the payload of one of ``devices`` ranks."""
+        if self.collective:
+            return nbytes / devices
         return (self.reads_per_elem + self.writes_per_elem) * nbytes
+
+    def bus_bytes_per_pass(self, nbytes: int, devices: int) -> float:
+        """Bytes each rank of a collective sends in one pass, at least."""
+        return self.bus_factor(devices) * self.bytes_per_pass(nbytes, devices)
+
+    def bus_factor(self, devices: int) -> float:
+        if not self.collective:
+            raise ValueError(f"mix {self.name!r} is not a collective")
+        return BUS_FACTORS[self.collective](devices)
 
     def flops_per_pass(self, n_elems: int) -> float:
         return self.flops_per_elem * n_elems
 
     def supports(self, backend: str) -> bool:
+        if self.collective:     # needs the mesh itself, not an oracle alias
+            return backend in self.backends
         return _BACKEND_ALIASES.get(backend, backend) in self.backends
 
 
@@ -179,15 +214,29 @@ def _build_registry() -> dict[str, MixDef]:
 
 _REGISTRY: dict[str, MixDef] = _build_registry()
 
+#: the collective mixes, kept apart from the instruction mixes above: their
+#: bytes follow the group size, not per-element constants
+_COLLECTIVES: dict[str, MixDef] = {
+    "all_reduce": MixDef(
+        "all_reduce", 0.0, reads_per_elem=0.0, backends=("sharded",),
+        collective="all_reduce",
+        description="collective: each mesh shard is one rank's message, "
+                    "summed over the ranks and left on every rank; bytes "
+                    "are the payload a rank (algbw)"),
+}
+
 
 def registry() -> dict[str, MixDef]:
-    """name -> MixDef for every known mix (shared, do not mutate)."""
+    """name -> MixDef for every instruction mix (shared, do not mutate);
+    the collective mixes are ``collective_names()``."""
     return dict(_REGISTRY)
 
 
 def get_mix(name: str) -> MixDef:
     if name in _REGISTRY:
         return _REGISTRY[name]
+    if name in _COLLECTIVES:
+        return _COLLECTIVES[name]
     if name.startswith("fma_"):
         # the fma family is open-ended: any positive chain depth is a valid
         # mix (registry() lists only the canonical ladder)
@@ -206,7 +255,8 @@ def get_mix(name: str) -> MixDef:
                 return rw_ratio(int(m.group(1)), int(m.group(2)))
             except ValueError as e:
                 raise KeyError(str(e)) from None
-    raise KeyError(f"unknown mix {name!r}; known: {sorted(_REGISTRY)}")
+    raise KeyError(f"unknown mix {name!r}; known: "
+                   f"{sorted(_REGISTRY) + sorted(_COLLECTIVES)}")
 
 
 def _sort_key(m: MixDef) -> tuple:
@@ -224,4 +274,11 @@ def mix_names(backend: str | None = None) -> list[str]:
     """All mix names, optionally only those a given backend supports, in
     deterministic order (sorted by family parameter, then name)."""
     return [m.name for m in sorted(_REGISTRY.values(), key=_sort_key)
+            if backend is None or m.supports(backend)]
+
+
+def collective_names(backend: str | None = None) -> list[str]:
+    """The collective mixes, optionally only those ``backend`` runs
+    (``mix_names`` lists the instruction mixes)."""
+    return [name for name, m in sorted(_COLLECTIVES.items())
             if backend is None or m.supports(backend)]

@@ -172,6 +172,11 @@ class Runner:
                     if mix.chase:
                         bpc, fpc = _chase_accounting(mix, spec, real_bytes,
                                                      n_elems, passes)
+                    elif mix.collective:
+                        # the payload a rank: the 1/devices shard
+                        bpc = mix.bytes_per_pass(real_bytes,
+                                                 spec.devices) * passes
+                        fpc = mix.flops_per_pass(n_elems) * passes
                     else:
                         bpc = mix.bytes_per_pass(real_bytes) * passes
                         fpc = mix.flops_per_pass(n_elems) * passes

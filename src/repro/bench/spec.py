@@ -109,7 +109,10 @@ class BenchSpec:
             if not backend.supports(mix):
                 raise BenchSpecError(
                     f"mix {m!r} is not supported by backend "
-                    f"{self.backend!r} (declared: {mix.backends})")
+                    f"{self.backend!r} (declared: {mix.backends})"
+                    + (" [rule: a collective mix exchanges shards between "
+                       "mesh devices, so only the sharded backend runs it]"
+                       if mix.collective else ""))
             if self.load > 0 and not mix.chase:
                 raise BenchSpecError(
                     f"load={self.load} co-schedules bandwidth generators "

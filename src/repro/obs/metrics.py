@@ -18,6 +18,9 @@ set is open, these are the ones the built-in instrumentation emits):
     passloop_chain_write           chain through an optimization barrier
                                    (compiled array mixes) or a one-element
                                    write into the working set (the rest)
+    collective_cases_built         collective pass loops built
+                                   (``core.collective_bench.make_passloop``;
+                                   they chain through a barrier too)
 
 Gauges:
 

@@ -1,6 +1,7 @@
 """Deviceless compiles for one TPU v5e chip: every registered Pallas mix and
 the main ``xla`` mixes, built by the bench's own backends at a 1 GiB f32
-working set and compiled for a described (not attached) v5e.  A compile here
+working set and compiled for a described (not attached) v5e; and the
+``all_reduce`` collective over the four chips of a described v5e 2x2 host.  A compile here
 is what the chip's compiler accepts or refuses; nothing runs and nothing is
 measured.  The topology is described inside a fixture, never at import: only
 one process at a time may load the TPU library."""
@@ -36,16 +37,22 @@ def topo():
 
 
 @pytest.fixture(scope="module")
-def one_chip(topo):
-    """One described chip, with the persistent compilation cache off: a
-    deviceless compile is written to it but cannot be read back."""
+def no_compile_cache():
+    """The persistent compilation cache off: a deviceless compile is
+    written to it but cannot be read back."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_compile_cache):
+    """One described chip."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -175,3 +182,41 @@ def test_compiled_scalar_passloop_still_writes_working_set(one_chip, on_tpu):
     assert len(_defines(_while_body(comps),
                         rf"{vmem} dynamic-update-slice\(")) == 1
     assert _defines(comps["ENTRY"], rf"{vmem} copy-done\(")
+
+
+@pytest.mark.parametrize("unroll", [1, 2])
+def test_all_reduce_passloop_compiles_for_v5e_2x2(topo, no_compile_cache,
+                                                   monkeypatch, unroll):
+    """The ``all_reduce`` mix's case on the sharded backend, over the four
+    described chips of a v5e 2x2 host, 1 GiB in all (256 MiB a rank): the
+    module ``jit_collective_passloop_all_reduce``, whose loop body runs
+    ``unroll`` all-reduces of a whole message a trip (none hoisted, none
+    merged), whose entry makes no copy of the message, and whose
+    arguments, output and temporaries fit each chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.bench.backends import ShardedBackend
+    backend = ShardedBackend()          # its own mesh cache, on topo's chips
+    monkeypatch.setattr(backend, "_mesh_devices", lambda: list(topo.devices))
+    passes = 8
+    spec = BenchSpec(mixes=("all_reduce",), sizes=(NBYTES,),
+                     backend="sharded", devices=4, passes=passes,
+                     unroll=unroll)
+    case = backend.make_case(spec, get_mix("all_reduce"), SHAPE,
+                             jnp.float32, passes)
+    x = jax.ShapeDtypeStruct(SHAPE, jnp.float32, sharding=NamedSharding(
+        backend._mesh(4), P("d", None)))
+    compiled = jax.jit(case).lower(x).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_collective_passloop_all_reduce,"), \
+        hlo.splitlines()[0]
+    message = rf"f32\[{SHAPE[0] // 4},{SHAPE[1]}\]"
+    comps = _computations(hlo)
+    exchanges = _defines(_while_body(comps),
+                         rf"{message}\S* all-reduce(-start)?\(")
+    assert len(exchanges) == unroll, (unroll, exchanges)
+    assert not _defines(comps["ENTRY"], rf"{message}\S* copy(-start)?\(")
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes == NBYTES // 4
+    assert ma.temp_size_in_bytes <= unroll * NBYTES // 4 + 2**20
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) < HBM_BYTES

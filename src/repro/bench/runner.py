@@ -17,6 +17,7 @@ them, and a cached case never closes over a buffer (see bench.backends).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.bench.backends import get_backend
@@ -198,6 +199,9 @@ class Runner:
                                             value=spec.value)
                     if prepare is not None:  # e.g. sharded: one mesh
                         x = prepare(spec, x)  # placement, shared per size
+                    # the fill is async: block so the span ends when the
+                    # buffer exists, not when its fill is enqueued
+                    x = jax.block_until_ready(x)
                 metrics.REGISTRY.inc("buffers_built")
                 metrics.REGISTRY.gauge_max("peak_working_set_bytes",
                                            real_bytes)

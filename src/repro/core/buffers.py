@@ -4,24 +4,57 @@ x86-membench initializes buffers with a cycle of a user-defined number, its
 reciprocal, and the additive inverses of both: (v, 1/v, -v, -1/v).  This
 guarantees no denormals (which stall FP pipelines) while keeping non-trivial
 data (data values influence power draw and, under power caps, throughput —
-paper §2/§3.2).  Kept verbatim here, property-tested in tests/test_core.py.
+paper §2/§3.2).  Property-tested in tests/test_core.py.
+
+The four values are computed in float64 and cast to the buffer's dtype on
+the host; the buffer itself is built on the device by one jitted
+broadcast/iota fusion (``_fill``) that only selects among those four
+already-cast values.  A cast rounds each element alone, whatever the array's
+length, so the buffer holds the same bits as the float64 cycle tiled to full
+length on the host and cast whole (the reference in tests/test_core.py),
+with no host array of the working set's size and no host-to-device copy.
 """
 from __future__ import annotations
 
-import numpy as np
+import functools
+
+import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 DEFAULT_VALUE = 1.234567
 
 
-def init_pattern(n: int, value: float = DEFAULT_VALUE, dtype=jnp.float32):
-    """(v, 1/v, -v, -1/v) cycled to length n."""
+@functools.partial(jax.jit, static_argnums=1)
+def _fill(cycle, shape):
+    """``cycle`` (4,) repeated over ``shape`` in row-major order: element i
+    of the flattened buffer is ``cycle[i % 4]``.  Pure selection, so every
+    element is one of the four given values, bit for bit.  ``i % 4`` is
+    summed per axis from small residues, so no index overflows int32."""
+    phase = jnp.zeros(shape, jnp.int32)
+    stride = 1
+    for axis in reversed(range(len(shape))):
+        pos = lax.rem(lax.broadcasted_iota(jnp.int32, shape, axis), 4)
+        phase = phase + pos * (stride % 4)
+        stride *= shape[axis]
+    return lax.select_n(lax.rem(phase, 4),
+                        *(jnp.broadcast_to(c, shape) for c in cycle))
+
+
+def _cycle(value: float, dtype):
+    """The four values (v, 1/v, -v, -1/v), computed in float64 and cast to
+    ``dtype`` on the host.  The cast is elementwise, so each value rounds as
+    it would inside a buffer of any length cast whole."""
     if value == 0 or not np.isfinite(value):
         raise ValueError("init value must be finite and nonzero")
     cycle = np.array([value, 1.0 / value, -value, -1.0 / value], dtype=np.float64)
-    buf = np.tile(cycle, n // 4 + 1)[:n]
-    arr = jnp.asarray(buf, dtype=dtype)
-    return arr
+    return jnp.asarray(cycle, dtype=dtype)
+
+
+def init_pattern(n: int, value: float = DEFAULT_VALUE, dtype=jnp.float32):
+    """(v, 1/v, -v, -1/v) cycled to length n, built on the default device."""
+    return _fill(_cycle(value, dtype), (n,))
 
 
 def working_set_shape(nbytes: int, dtype=jnp.float32, lanes: int = 128
@@ -36,16 +69,14 @@ def working_set_shape(nbytes: int, dtype=jnp.float32, lanes: int = 128
 def working_set(nbytes: int, dtype=jnp.float32, value: float = DEFAULT_VALUE,
                 lanes: int = 128):
     """A 2D (rows, lanes) buffer of ~nbytes — 2D so Pallas BlockSpecs tile it
-    natively ((8,128)-aligned, the v5e register tile)."""
-    rows, lanes = working_set_shape(nbytes, dtype, lanes)
-    n = rows * lanes
+    natively ((8,128)-aligned, the v5e register tile).  Built on the device
+    in place (``_fill``): the only allocation is the buffer itself."""
+    shape = working_set_shape(nbytes, dtype, lanes)
     if jnp.issubdtype(dtype, jnp.integer):
-        cycle = np.array([1, 7, -1, -7], dtype=np.int64)
-        buf = np.tile(cycle, n // 4 + 1)[:n].astype(np.dtype(dtype.name
-                                                             if hasattr(dtype, "name")
-                                                             else dtype))
-        return jnp.asarray(buf).reshape(rows, lanes)
-    return init_pattern(n, value, dtype).reshape(rows, lanes)
+        cycle = jnp.asarray(np.array([1, 7, -1, -7]).astype(jnp.dtype(dtype)))
+    else:
+        cycle = _cycle(value, dtype)
+    return _fill(cycle, shape)
 
 
 def has_denormals(arr) -> bool:

@@ -1,4 +1,5 @@
 """Core membench: buffer discipline (hypothesis), timing, sweep, analysis."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -35,6 +36,64 @@ def test_working_set_size(nbytes):
     real = x.size * x.dtype.itemsize
     assert abs(real - nbytes) / nbytes < 0.3 or real >= 8 * 128 * 4
     assert x.shape[1] == 128 and x.shape[0] % 8 == 0
+
+
+def _host_pattern(n, value, dtype):
+    """The host construction, kept as the reference: the float64 cycle
+    tiled to n on the host, then cast whole."""
+    cycle = np.array([value, 1.0 / value, -value, -1.0 / value], np.float64)
+    return np.asarray(jnp.asarray(np.tile(cycle, n // 4 + 1)[:n], dtype=dtype))
+
+
+def _host_working_set(nbytes, dtype, value, lanes):
+    rows, lanes = buffers.working_set_shape(nbytes, dtype, lanes)
+    n = rows * lanes
+    if jnp.issubdtype(dtype, jnp.integer):
+        cycle = np.array([1, 7, -1, -7], dtype=np.int64)
+        buf = np.tile(cycle, n // 4 + 1)[:n].astype(np.dtype(dtype))
+        return np.asarray(jnp.asarray(buf)).reshape(rows, lanes)
+    return _host_pattern(n, value, dtype).reshape(rows, lanes)
+
+
+@pytest.mark.parametrize("value", [1.234567, 2.0, 1.3579])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16,
+                                   jnp.int32])
+def test_device_pattern_bit_identical_to_host_construction(dtype, value):
+    """The pattern filled on the device holds the very bytes of the host
+    construction: for lengths that are not multiples of 4 and for
+    (rows, lanes) buffers whose lanes are not either."""
+    dtype = jnp.dtype(dtype)
+    built = []
+    for n in (1, 6, 4099):
+        got = np.asarray(buffers.init_pattern(n, value, dtype))
+        want = _host_pattern(n, value, dtype)
+        assert (got.dtype, got.shape) == (want.dtype, want.shape)
+        assert got.tobytes() == want.tobytes(), n
+        built.append(got)
+    for nbytes, lanes in ((32 * 1024, 128), (5000, 6)):
+        got = np.asarray(buffers.working_set(nbytes, dtype, value, lanes))
+        want = _host_working_set(nbytes, dtype, value, lanes)
+        assert (got.dtype, got.shape) == (want.dtype, want.shape)
+        assert got.tobytes() == want.tobytes(), (nbytes, lanes)
+        built.append(got)
+    if not jnp.issubdtype(dtype, jnp.integer):
+        assert not any(buffers.has_denormals(a) for a in built)
+
+
+def test_pattern_fill_compiles_once_with_no_temporary():
+    """One compile per shape and dtype serves every value (the cycle is a
+    traced argument), and the fill allocates nothing but its output: one
+    working set at a time, as the Runner's peak gauge assumes."""
+    lanes = 24      # a shape no other test fills
+    before = buffers._fill._cache_size()
+    for value in (1.5, 2.0, 3.25, -0.75):
+        buffers.working_set(48 * 1024, jnp.float32, value, lanes)
+    assert buffers._fill._cache_size() == before + 1
+    shape = buffers.working_set_shape(48 * 1024, jnp.float32, lanes)
+    ma = buffers._fill.lower(jax.ShapeDtypeStruct((4,), jnp.float32),
+                             shape).compile().memory_analysis()
+    assert ma.output_size_in_bytes == 48 * 1024
+    assert ma.temp_size_in_bytes <= ma.output_size_in_bytes // 64
 
 
 def test_init_rejects_bad_values():

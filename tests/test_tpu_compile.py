@@ -1,7 +1,8 @@
 """Deviceless compiles for one TPU v5e chip: every registered Pallas mix and
 the main ``xla`` mixes, built by the bench's own backends at a 1 GiB f32
 working set and compiled for a described (not attached) v5e; and the
-``all_reduce`` collective over the four chips of a described v5e 2x2 host.  A compile here
+``all_reduce`` collective over the four chips of a described v5e 2x2 host;
+and the Runner's working-set fill (``core.buffers._fill``).  A compile here
 is what the chip's compiler accepts or refuses; nothing runs and nothing is
 measured.  The topology is described inside a fixture, never at import: only
 one process at a time may load the TPU library."""
@@ -220,3 +221,23 @@ def test_all_reduce_passloop_compiles_for_v5e_2x2(topo, no_compile_cache,
     assert ma.temp_size_in_bytes <= unroll * NBYTES // 4 + 2**20
     assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("dtype,hlo_type", [(jnp.float32, "f32"),
+                                            (jnp.bfloat16, "bf16")])
+def test_working_set_fill_compiles_to_one_fusion_for_v5e(dtype, hlo_type,
+                                                        one_chip):
+    """The Runner's 1 GiB working-set build, as the chip compiles it: one
+    fusion writes the whole buffer from the four-value cycle, and the only
+    allocation of the working set's size is that output."""
+    from repro.core import buffers
+    shape = working_set_shape(NBYTES, dtype)
+    compiled = buffers._fill.lower(
+        jax.ShapeDtypeStruct((4,), dtype, sharding=one_chip), shape).compile()
+    full = rf"{hlo_type}\[{shape[0]},{shape[1]}\]"
+    entry = _computations(compiled.as_text())["ENTRY"]
+    assert len(_defines(entry, rf"{full}\S* fusion\(")) == 1
+    assert len(_defines(entry, rf"{full}\S* \S+\(")) == 1
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes == NBYTES
+    assert ma.temp_size_in_bytes <= 2**20

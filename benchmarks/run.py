@@ -58,11 +58,6 @@ def _figure(module: str, **kw):
     return run
 
 
-def _roofline(quick: bool):
-    from benchmarks import roofline_table
-    roofline_table.main()
-
-
 #: (name, title, fn(quick)) of the in-process entries, in output order
 IN_PROCESS_ENTRIES = (
     ("bench", "bench: unified experiment API smoke (python -m repro.bench)",
@@ -81,8 +76,6 @@ IN_PROCESS_ENTRIES = (
      _figure("fig7_loaded_latency")),
     ("table1", "table1: machine models (documented vs measured)",
      _figure("table1_machine")),
-    ("roofline", "roofline: 40-cell dry-run table (reads artifacts/dryrun)",
-     _roofline),
 )
 
 
@@ -91,7 +84,7 @@ def main() -> int:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma list: bench,fig1,fig2,fig3,fig4,fig5,fig6,"
-                         "fig7,table1,collectives,roofline")
+                         "fig7,table1,collectives")
     args = ap.parse_args()
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None

@@ -1,4 +1,5 @@
-"""Quickstart: characterize the machine, then train a small LM for 30 steps.
+"""Quickstart: measure this machine's memory hierarchy and print the
+per-level table.
 
     PYTHONPATH=src python examples/quickstart.py
 
@@ -6,19 +7,12 @@ The measurement is one declarative BenchSpec executed by the repro.bench
 Runner — the same API behind ``python -m repro.bench run`` (see
 src/repro/bench/README.md for the knob -> paper mapping).
 """
-import jax
-
 from repro.bench import BenchSpec, Runner
-from repro.configs import get_arch, reduced
 from repro.core import analysis
 from repro.core.machine_model import detect_host
-from repro.launch.mesh import make_mesh
-from repro.optim import adamw
-from repro.train.trainer import TrainConfig, Trainer
 
 
 def main():
-    # 1. membench: measure this machine's memory hierarchy (the paper's tool)
     print("== membench: hierarchy sweep (quick) ==")
     spec = BenchSpec(mixes=("load_sum", "fma_8"),
                      sizes=(32 * 2**10, 1 * 2**20, 16 * 2**20),
@@ -26,19 +20,6 @@ def main():
     res = Runner().run(spec)
     model = analysis.build_machine_model(res, detect_host())
     print(analysis.format_table(model.level_bw, model.mix_penalty))
-
-    # 2. train a reduced granite for 30 steps on a named 3-axis mesh
-    print("\n== train: granite-3-2b (reduced) 30 steps ==")
-    cfg = reduced(get_arch("granite-3-2b"))
-    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
-    tcfg = TrainConfig(steps=30, ckpt_every=15, ckpt_dir="/tmp/quickstart_ckpt",
-                       log_every=5,
-                       opt=adamw.AdamWConfig(lr=1e-3, warmup_steps=5,
-                                             total_steps=30))
-    trainer = Trainer(cfg, (8, 128), mesh, tcfg)
-    _, _, hist = trainer.train(resume=False)
-    print(f"\nloss {hist[0]['loss']:.3f} -> {hist[-1]['loss']:.3f} "
-          f"over {tcfg.steps} steps")
 
 
 if __name__ == "__main__":

@@ -1,4 +1,4 @@
-"""Production mesh factory.
+"""Mesh factory.
 
 A function, not a module-level constant: importing this module never touches
 jax device state (device count is locked at first jax init).
@@ -8,16 +8,8 @@ from __future__ import annotations
 import jax
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (elastic scaling / tests).  Axis names must come from
-    {pod, data, model} so the sharding rules apply unchanged."""
-    assert set(axes) <= {"pod", "data", "model"}, axes
+    """A mesh of ``shape`` over the local devices, with every axis
+    ``Auto``."""
     return jax.make_mesh(shape, axes,
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -1,6 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests see 1 device by design;
 multi-device tests spawn subprocesses with the flag set explicitly."""
-import jax
 import pytest
 
 
@@ -11,13 +10,3 @@ def _ledger_isolation(tmp_path, monkeypatch):
     from repro.obs import ledger
     monkeypatch.setenv(ledger.LEDGER_ENV, str(tmp_path / "BENCH_history"))
 
-
-@pytest.fixture(scope="session")
-def smoke_ctx():
-    from repro.distributed.sharding import make_smoke_ctx
-    return make_smoke_ctx()
-
-
-@pytest.fixture(scope="session")
-def rng():
-    return jax.random.key(0)

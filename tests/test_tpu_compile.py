@@ -1,5 +1,5 @@
-"""Deviceless compiles for one TPU v5e chip: every registered Pallas mix and
-the main ``xla`` mixes, built by the bench's own backends at a 1 GiB f32
+"""Deviceless compiles for one TPU v5e chip: every registered Pallas and
+``xla`` mix, built by the bench's own backends at a 1 GiB f32
 working set and compiled for a described (not attached) v5e; and the
 ``all_reduce`` collective over the four chips of a described v5e 2x2 host;
 and the Runner's working-set fill (``core.buffers._fill``).  A compile here
@@ -23,6 +23,9 @@ NBYTES = 2**30
 SHAPE = working_set_shape(NBYTES)
 HBM_BYTES = 16 * 10**9          # one v5e chip (Google Cloud, "TPU v5e")
 PALLAS_MIXES = [m for m in mix_names("pallas") if not get_mix(m).chase]
+#: the Pallas mixes whose kernel writes arrays, and those that return a scalar
+ARRAY_MIXES = [m for m in PALLAS_MIXES if get_mix(m).writes_per_elem]
+SCALAR_MIXES = [m for m in PALLAS_MIXES if not get_mix(m).writes_per_elem]
 
 
 @pytest.fixture(scope="module")
@@ -130,7 +133,7 @@ def test_pallas_mix_compiles_for_v5e(name, one_chip, on_tpu):
                      rf'custom_call_target="tpu_custom_call"', hlo), name
 
 
-@pytest.mark.parametrize("name", ["copy", "triad", "load_sum"])
+@pytest.mark.parametrize("name", mix_names("xla"))
 def test_xla_mix_compiles_for_v5e(name, one_chip, on_tpu):
     hlo = _compile(get_backend("xla"), name, one_chip)
     assert "tpu_custom_call" not in hlo, name
@@ -148,35 +151,42 @@ WS = rf"f32\[{SHAPE[0]},{SHAPE[1]}\]"      # a working set's shape in HLO
 
 
 @pytest.mark.parametrize("unroll", [1, 2, 4])
-@pytest.mark.parametrize("name", ["copy", "triad", "rw_2to1"])
+@pytest.mark.parametrize("name", ARRAY_MIXES)
 def test_compiled_array_passloop_reads_working_set_in_place(
         name, unroll, one_chip, on_tpu):
     """A compiled kernel's sweeps are chained through an optimization
     barrier, not a write into the working set: the entry makes no copy of
     any working set for the loop to write, the loop body runs ``unroll``
-    kernel calls a trip (none hoisted, none merged), and the temporaries
-    are the rotating output slots alone.  ``passes`` is two trips at every
-    ``unroll``: XLA inlines a loop of one trip, leaving no body to read."""
+    kernel calls a trip (none hoisted, none merged), each returning one
+    working set per write stream, and the temporaries are the rotating
+    output slots alone.  ``passes`` is two trips at every ``unroll``: XLA
+    inlines a loop of one trip, leaving no body to read."""
+    writes = int(get_mix(name).writes_per_elem)
     compiled = _compiled(get_backend("pallas"), name, one_chip,
                          passes=2 * unroll, unroll=unroll)
     comps = _computations(compiled.as_text())
     assert not _defines(comps["ENTRY"], rf"{WS}\S* copy(-start)?\("), name
+    result = ", ".join([rf"{WS}\S*"] * writes)
+    if writes > 1:
+        result = rf"\({result}\)"
     kernels = _defines(_while_body(comps),
-                       rf"{WS}\S* custom-call\(.*\), "
+                       rf"{result} custom-call\(.*\), "
                        rf'custom_call_target="tpu_custom_call"')
     assert len(kernels) == unroll, (name, unroll, len(kernels))
     assert all(f"%membench_{name}." in k.split("=")[0] for k in kernels)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp <= unroll * NBYTES + 2**20, (name, unroll, temp)
+    assert temp <= unroll * writes * NBYTES + 2**20, (name, unroll, temp)
 
 
-def test_compiled_scalar_passloop_still_writes_working_set(one_chip, on_tpu):
+@pytest.mark.parametrize("name", SCALAR_MIXES)
+def test_compiled_scalar_passloop_still_writes_working_set(name, one_chip,
+                                                          on_tpu):
     """The scalar path keeps its one-element write into the carried
-    buffer: at 1 MiB ``load_sum``'s pass loop stages the working set in
+    buffer: at 1 MiB a scalar mix's pass loop stages the working set in
     VMEM (memory space ``S(1)``) and updates it there every pass."""
     nbytes = 2**20
     rows, cols = working_set_shape(nbytes)
-    hlo = _compiled(get_backend("pallas"), "load_sum", one_chip,
+    hlo = _compiled(get_backend("pallas"), name, one_chip,
                     nbytes=nbytes, passes=4).as_text()
     comps = _computations(hlo)
     vmem = rf"f32\[{rows},{cols}\]\{{1,0:T\(8,128\)S\(1\)\}}"

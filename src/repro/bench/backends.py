@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.bench.mixes import MixDef, get_mix, interleavable
 from repro.bench.spec import BenchSpec, BenchSpecError, knob_names
-from repro.obs import trace
+from repro.obs import metrics, trace
 
 
 #: BenchSpec fields that can NEVER change what make_case compiles — either
@@ -574,20 +574,24 @@ class PallasBackend(_CaseBackend):
     and compiled on a TPU.
     """
     name = "pallas"
-    DEFAULT_BLOCK_ROWS = 128
 
     def supports(self, mix: MixDef) -> bool:
         return self.name in mix.backends
 
-    def _resolve(self, spec: BenchSpec, rows: int) -> int:
+    def _resolve(self, spec: BenchSpec, mix: MixDef, shape, dtype) -> int:
+        """The tile's rows: an explicit ``block_rows`` as given; the chase's
+        tile, which is the extent of its pointer cycle and no transfer
+        unit, by ``floor_block_rows``; every other mix by the VMEM budget
+        of its double-buffered read and write streams
+        (``default_block_rows``)."""
+        from repro.kernels.membench import membench as mb
         if spec.block_rows is not None:
             return spec.block_rows       # explicit knob: never adjusted
-        # default tiling must divide the buffer: largest sublane multiple
-        # <= 128 that does (rows is always a multiple of 8, so 8 divides)
-        r = min(self.DEFAULT_BLOCK_ROWS, rows)
-        while r > 8 and rows % r:
-            r -= 8
-        return r
+        if mix.chase:
+            return mb.floor_block_rows(shape[0])
+        return mb.default_block_rows(
+            shape[0], jnp.dtype(dtype).itemsize,
+            int(mix.reads_per_elem + mix.writes_per_elem), spec.streams)
 
     def validate(self, spec: BenchSpec) -> None:
         from repro.kernels.membench.membench import resolve_interpret
@@ -614,8 +618,13 @@ class PallasBackend(_CaseBackend):
 
     def make_case(self, spec, mix, shape, dtype, passes):
         from repro.kernels.membench import ops as mb_ops
-        from repro.kernels.membench.membench import resolve_interpret
-        rows = self._resolve(spec, shape[0])
+        from repro.kernels.membench.membench import (floor_block_rows,
+                                                     resolve_interpret)
+        rows = self._resolve(spec, mix, shape, dtype)
+        metrics.REGISTRY.inc(
+            "pallas_tile_explicit" if spec.block_rows is not None
+            else "pallas_tile_budget" if rows > floor_block_rows(shape[0])
+            else "pallas_tile_default")
         if rows > shape[0] or shape[0] % rows:
             raise BenchSpecError(
                 f"block_rows {rows} does not divide {shape[0]} rows")
@@ -655,7 +664,7 @@ class PallasBackend(_CaseBackend):
             # one pointer cycle per VMEM tile: the grid walks the tiles, the
             # kernel chases the current tile's TILE-LOCAL cycle
             from repro.core.instruction_mix import chase_perm
-            rows = self._resolve(spec, x.shape[0])
+            rows = self._resolve(spec, mix, x.shape, x.dtype)
             perm = jnp.asarray(chase_perm(x.shape, x.shape[0] // rows))
             if spec.load:
                 return lambda: case(perm, x)

@@ -4,6 +4,10 @@ explicit VMEM tiling.
 Knobs (mapping to the paper, DESIGN.md §2):
   mix         load_only | load_sum | copy | fma_k | mxu     (C2: LOAD/FADD/NOP)
   block_rows  rows per (block_rows, 128) VMEM tile           (C4: LD1D/LD2D/LD4D)
+              -- unset, the bench takes ``default_block_rows``: the largest
+              power-of-two tile whose double-buffered streams fit
+              ``VMEM_BUDGET`` and leave ``MIN_GRID_STEPS`` steps a pass,
+              never smaller than ``floor_block_rows``
   streams     1 = sequential block walk (post-increment analogue);
               S > 1 = S interleaved streams via the index_map (the paper's
               four offset address pointers breaking AGU dependencies)   (C3)
@@ -29,6 +33,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: VMEM the pipeline's double-buffered tiles may take: half of Mosaic's
+#: default scoped VMEM limit on v5e (16 MiB), the rest left to its scratch
+VMEM_BUDGET = 8 * 2**20
+#: fewest grid steps a default tile leaves a pass, so the DMA of step i+1
+#: still overlaps step i
+MIN_GRID_STEPS = 16
+#: the tile the default never goes below (when it divides the rows)
+FLOOR_BLOCK_ROWS = 128
+
 #: the revisited scalar accumulator of the load-family and chase kernels
 _SCALAR_OUT = pl.BlockSpec((1, 1), lambda i: (0, 0),
                            memory_space=pltpu.SMEM)
@@ -46,6 +59,34 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
         raise ValueError(f"Pallas interpret mode requested on a {platform} "
                          f"device; membench kernels compile there")
     return interpret
+
+
+def floor_block_rows(rows: int) -> int:
+    """The largest sublane multiple <= ``FLOOR_BLOCK_ROWS`` that divides
+    ``rows`` (a multiple of 8, so 8 always does)."""
+    r = min(FLOOR_BLOCK_ROWS, rows)
+    while r > 8 and rows % r:
+        r -= 8
+    return r
+
+
+def default_block_rows(rows: int, itemsize: int, n_streams: int,
+                       streams: int = 1, lanes: int = 128) -> int:
+    """The default tile of a streaming kernel over ``rows`` x ``lanes``
+    elements of ``itemsize`` bytes whose pipeline double-buffers
+    ``n_streams`` tiles (read plus write streams): the largest power-of-two
+    row count that fits ``n_streams`` x 2 tiles in ``VMEM_BUDGET``, leaves
+    at least ``MIN_GRID_STEPS`` grid steps, divides ``rows`` into a block
+    count ``streams`` divides; ``floor_block_rows`` when no such tile is
+    larger than it.  From 256 rows up a power of two is a multiple of every
+    dtype's sublane tile."""
+    best, tile = floor_block_rows(rows), 2 * FLOOR_BLOCK_ROWS
+    while (n_streams * 2 * tile * lanes * itemsize <= VMEM_BUDGET
+           and rows // tile >= MIN_GRID_STEPS):
+        if rows % tile == 0 and (rows // tile) % streams == 0:
+            best = tile
+        tile *= 2
+    return best
 
 
 def _mix_body(mix: str, depth: int, blk, w=None, interleave: int = 1):

@@ -18,6 +18,11 @@ set is open, these are the ones the built-in instrumentation emits):
     passloop_chain_write           chain through an optimization barrier
                                    (compiled array mixes) or a one-element
                                    write into the working set (the rest)
+    pallas_tile_budget /           Pallas cases built whose tile the VMEM
+    pallas_tile_default /          budget rule enlarged past the floor tile,
+    pallas_tile_explicit           that kept the floor tile, or that took an
+                                   explicit ``block_rows``
+                                   (``kernels.membench.default_block_rows``)
     collective_cases_built         collective pass loops built
                                    (``core.collective_bench.make_passloop``;
                                    they chain through a barrier too)

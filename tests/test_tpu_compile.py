@@ -67,9 +67,10 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _compiled(backend, name, sharding, nbytes=NBYTES, passes=1, unroll=1):
+def _compiled(backend, name, sharding, nbytes=NBYTES, passes=1, unroll=1,
+              **knobs):
     spec = BenchSpec(mixes=(name,), sizes=(nbytes,), backend=backend.name,
-                     passes=passes, unroll=unroll)
+                     passes=passes, unroll=unroll, **knobs)
     mix = get_mix(name)
     shape = working_set_shape(nbytes)
     backend.validate(spec)
@@ -145,6 +146,31 @@ def test_pallas_chase_refused_on_tpu(on_tpu):
     spec = BenchSpec(mixes=("latency_chase",), backend="pallas")
     with pytest.raises(BenchSpecError, match="R2"):
         get_backend("pallas").validate(spec)
+
+
+@pytest.mark.parametrize("name,rows", [("copy", 4096), ("triad", 2048),
+                                       ("rw_2to1", 2048)])
+def test_budget_tile_compiles_for_v5e(name, rows, one_chip, on_tpu):
+    """The default tile at 1 GiB is the VMEM budget's, larger than the
+    128-row floor, and Mosaic fits it in its scoped VMEM: the pass loop
+    compiles and holds the same HBM as with the floor tile."""
+    backend = get_backend("pallas")
+    spec = BenchSpec(mixes=(name,), sizes=(NBYTES,), backend="pallas")
+    assert backend._resolve(spec, get_mix(name), SHAPE, jnp.float32) == rows
+    budget, floor = (
+        _compiled(backend, name, one_chip, passes=2, **knobs)
+        .memory_analysis() for knobs in ({}, {"block_rows": 128}))
+    for field in ("argument_size_in_bytes", "output_size_in_bytes",
+                  "temp_size_in_bytes"):
+        assert getattr(budget, field) == getattr(floor, field), field
+
+
+def test_tile_past_vmem_is_refused_for_v5e(one_chip, on_tpu):
+    """The described compile sees a tile that overflows VMEM: four times
+    triad's budget tile (six 4 MiB buffers) is refused."""
+    with pytest.raises(jax.errors.JaxRuntimeError, match="memory space vmem"):
+        _compiled(get_backend("pallas"), "triad", one_chip, passes=2,
+                  block_rows=8192)
 
 
 WS = rf"f32\[{SHAPE[0]},{SHAPE[1]}\]"      # a working set's shape in HLO
